@@ -119,14 +119,16 @@ def _sharded_scaling(backend: str = "sharded") -> None:
     device_set; without that check the MTPS growth would hold by
     construction).  Wall-clock pkg_steps_per_s is reported but not gated:
     emulated devices share the host's cores, so timing scaling is too noisy
-    for CI.  Subprocesses keep the parent single-device.  Runs for both the
-    pure-JAX ``sharded`` backend and the ``sharded_fused`` composition (one
-    Pallas whole-step kernel per device partition)."""
+    for CI.  Subprocesses keep the parent single-device, and run on the CPU
+    (``JAX_PLATFORMS=cpu``): a parent that has touched JAX holds any
+    accelerator, so these rows are CPU emulation on every machine.  Runs for
+    both the pure-JAX ``sharded`` backend and the ``sharded_fused``
+    composition (one Pallas whole-step kernel per device partition)."""
     src = os.path.abspath(os.path.join(os.path.dirname(__file__), "..",
                                        "src"))
     released = {}
     for ndev in (1, 2, 4):
-        env = dict(os.environ, PYTHONPATH=src,
+        env = dict(os.environ, PYTHONPATH=src, JAX_PLATFORMS="cpu",
                    XLA_FLAGS=f"--xla_force_host_platform_device_count={ndev}")
         out = subprocess.run(
             [sys.executable, "-c", textwrap.dedent(
@@ -136,7 +138,8 @@ def _sharded_scaling(backend: str = "sharded") -> None:
         mtps, rate = out.stdout.strip().split()[-2:]
         released[ndev] = float(mtps)
         row(f"fleet.{backend}_scale_dev{ndev}", 0.0,
-            f"released_mtps={float(mtps):.0f};pkg_steps_per_s={rate}")
+            f"released_mtps={float(mtps):.0f};pkg_steps_per_s={rate};"
+            f"devices=cpu-emulated")
     assert released[2] > 1.5 * released[1], (backend, released)
     assert released[4] > 1.5 * released[2], (backend, released)
 

@@ -21,6 +21,7 @@ import sys
 import time
 
 from benchmarks import common
+from repro.compile_cache import use_compile_cache
 
 _ROOT = os.path.abspath(os.path.join(os.path.dirname(__file__), ".."))
 # module → repo-root trajectory artifact (appended per --json run)
@@ -53,6 +54,7 @@ MODULES = [
 
 
 def main() -> None:
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--only", default="",
                     help="comma-separated bench suffixes to run")

@@ -183,6 +183,19 @@ def _engine(scfg: SchedulerConfig, fp: Fingerprint, backend: str,
     return FleetEngine(scfg, fp=fp, backend=backend, devices=devices)
 
 
+def engine(n_trials: int, mode: str, *, backend: str = "broadcast",
+           devices: int | None = None, cfg: dvfs.DVFSConfig | None = None,
+           fp: Fingerprint = FINGERPRINT,
+           filtration_impl: str = "incremental", plant: str = "pole"):
+    """The fleet engine `run` drives for one ``mode`` ("reactive_poll" for
+    the baseline, "v24") of an ``n_trials`` population: the same cached
+    object, so its backend and compiled programs can be inspected."""
+    cfg = dvfs.DVFSConfig() if cfg is None else cfg
+    return _engine(_scheduler_cfg(cfg, _pack(n_trials), mode,
+                                  filtration_impl, plant),
+                   fp, backend, devices)
+
+
 def run(key=None, n_trials: int = 2_000, n_steps: int = 3_000,
         kind: str = "inference", burn_in: int = 400,
         cfg: dvfs.DVFSConfig | None = None,
@@ -215,8 +228,6 @@ def run(key=None, n_trials: int = 2_000, n_steps: int = 3_000,
     Rth/τ draws reticle-neighbour correlated (0.0 keeps the historical
     i.i.d. population bit-identically).
     """
-    from repro.fleet import FleetEngine   # late import: engine ← core cycle
-
     # construct-per-call: a dataclass default argument would be built once
     # at import and shared by every caller (the FleetEngine bug class)
     cfg = dvfs.DVFSConfig() if cfg is None else cfg
@@ -235,9 +246,9 @@ def run(key=None, n_trials: int = 2_000, n_steps: int = 3_000,
                               tau.reshape(lane_shape), cfg.dt_ms)
 
     def survey(mode: str):
-        eng = _engine(_scheduler_cfg(cfg, lanes, mode, filtration_impl,
-                                     plant),
-                      fp, backend, devices)
+        eng = engine(n_trials, mode, backend=backend, devices=devices,
+                     cfg=cfg, fp=fp, filtration_impl=filtration_impl,
+                     plant=plant)
         pkg = None
         if plant == "pole":
             pkg = eng.sched.package_params(

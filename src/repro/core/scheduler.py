@@ -322,6 +322,10 @@ class ThermalScheduler:
                     f"[*{batch_shape}, {c.n_tiles}|1, n_poles], got "
                     f"{pkg.decay.shape} (build it with "
                     f"package_params(..., batch_shape=...))")
+            # the state owns its buffers: a donating engine call deletes
+            # them, and the caller's draws must survive it (the Monte-Carlo
+            # harness reuses one pole bank for its baseline and v24 fleets)
+            pkg = jax.tree_util.tree_map(jnp.copy, pkg)
         fill = self.fp.rho_min if filtration_fill is None else filtration_fill
 
         init_ft = (pdu_gate.init_filtration_stats

@@ -66,15 +66,11 @@ def initialize(coordinator: str = "127.0.0.1:8476", num_processes: int = 1,
 
     MUST run before any other jax call in the process — backend creation
     freezes the topology, so a late initialize raises inside jax.  On CPU
-    the collective transport is switched to gloo first (newer jaxlib makes
-    that the default and may drop the flag; the update is best-effort).
+    the collective transport is switched to gloo first.
     """
     global _INITIALIZED
     if num_processes > 1 and not _INITIALIZED:
-        try:
-            jax.config.update("jax_cpu_collectives_implementation", "gloo")
-        except Exception:   # flag removed once gloo became the default
-            pass
+        jax.config.update("jax_cpu_collectives_implementation", "gloo")
         jax.distributed.initialize(coordinator_address=coordinator,
                                    num_processes=num_processes,
                                    process_id=process_id)

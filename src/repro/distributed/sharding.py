@@ -224,24 +224,15 @@ def fleet_trace_spec(ndim: int, axis: str = FLEET_AXIS,
 
 
 def fleet_shard_map(f, mesh, in_specs, out_specs):
-    """`shard_map` across JAX versions with replication checking OFF.
+    """`jax.shard_map` with replication checking OFF.
 
     The sharded-fused fleet backend maps a `pallas_call` over the package
-    mesh; pallas has no replication rule, so `check_rep` (0.4.x) /
-    `check_vma` (newer top-level `jax.shard_map`) must be disabled.  The
-    out_specs still place every result, so disabling the check loses
+    mesh; pallas has no replication rule, so `check_vma` must be disabled.
+    The out_specs still place every result, so disabling the check loses
     nothing but the static verifier.
     """
-    if hasattr(jax, "shard_map"):
-        for kw in ({"check_vma": False}, {"check_rep": False}, {}):
-            try:                                 # pragma: no cover
-                return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                                     out_specs=out_specs, **kw)
-            except TypeError:
-                continue
-    from jax.experimental.shard_map import shard_map
-    return shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                     check_rep=False)
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 # ===================================================== activation constraints

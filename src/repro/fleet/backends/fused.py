@@ -14,7 +14,9 @@ statistics are re-derived exactly from the ring at every chunk boundary, so
 float drift cannot accumulate across a 90k-step soak; both filtration
 representations (`FiltrationStats` fast path and ring-buffer `Filtration`
 oracle) are accepted.  Verified against the pure-JAX engine to ≤1e-5
-(tests/test_fleet_fused.py); off-TPU the kernel runs in interpret mode.
+(tests/test_fleet_fused.py).  Off-TPU the kernel runs in interpret mode;
+the choice is made once, when the backend is built, and `describe()`
+names it (``fused[blk=128,compiled]`` / ``...,interpret]``).
 
 Active-lane masks never enter the kernel: padded capacity-pool lanes ride
 the 128-lane axis like any other package (the kernel already masks its OWN
@@ -26,6 +28,7 @@ unchanged.  The mask keeps the default replicated placement
 """
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 
 from repro.core import pdu_gate
@@ -44,7 +47,8 @@ class FusedBackend(FleetBackend):
         super().__init__(sched)
         self.block_packages = block_packages
         self.time_chunk = time_chunk
-        self.interpret = interpret
+        self.interpret = (jax.default_backend() != "tpu" if interpret is None
+                          else interpret)
         self._rom_plant = None
         plant = sched.plant
         if plant.family != "pole":
@@ -241,5 +245,12 @@ class FusedBackend(FleetBackend):
         )
         return state, tnl(temps), tnl(freqs)
 
+    def kernel_mode(self) -> str:
+        """``compiled`` / ``interpret`` for the Pallas kernel, or ``scan``
+        when the plant keeps this backend on the pure-JAX scan."""
+        if self.run_block is None:
+            return "scan"
+        return "interpret" if self.interpret else "compiled"
+
     def describe(self) -> str:
-        return f"{self.name}[blk={self.block_packages}]"
+        return f"{self.name}[blk={self.block_packages},{self.kernel_mode()}]"

@@ -35,7 +35,6 @@ import warnings
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 
 from repro.core.scheduler import (SchedulerOutput, SchedulerState,
                                   ThermalScheduler)
@@ -133,9 +132,9 @@ class ShardedBackend(FleetBackend):
         # replication rules, so keep the static verifier that would catch a
         # wrong scalar-leaf spec (the checks-off `fleet_shard_map` wrapper
         # is only for the pallas_call in the sharded_fused subclass)
-        fn = shard_map(self.sched.update, mesh=self.mesh,
-                       in_specs=(self._state_specs, fleet_trace_spec(2)),
-                       out_specs=(self._state_specs, self._out_specs))
+        fn = jax.shard_map(self.sched.update, mesh=self.mesh,
+                           in_specs=(self._state_specs, fleet_trace_spec(2)),
+                           out_specs=(self._state_specs, self._out_specs))
         return fn(state, rho)
 
     # -- placement --------------------------------------------------------

@@ -79,6 +79,7 @@ class ShardedFusedBackend(ShardedBackend):
 
     def describe(self) -> str:
         # parent renders the mesh (and process span, when distributed);
-        # append the kernel's lane-block size inside the brackets
+        # append the kernel's lane-block size and mode inside the brackets
         return (super().describe()[:-1]
-                + f",blk={self._fused.block_packages}]")
+                + f",blk={self._fused.block_packages},"
+                f"{self._fused.kernel_mode()}]")

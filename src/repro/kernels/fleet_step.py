@@ -142,8 +142,13 @@ def _kernel(rho_ref, gamma_ref, buf0_ref, th0_ref, stats0_ref, freq0_ref,
         gdiag = jnp.sum(jnp.where(rows == cols, gamma, 0.0), axis=1,
                         keepdims=True)                       # [tp, 1]
 
+    # ask the MXU for full f32 precision: a single bf16 pass would round
+    # the density ring and Γ products to ~3 significant digits
+    hi = jax.lax.Precision.HIGHEST
+
     def couple(x):                                           # Γ @ x over tiles
-        return jnp.dot(gamma, x, preferred_element_type=jnp.float32)
+        return jnp.dot(gamma, x, precision=hi,
+                       preferred_element_type=jnp.float32)
 
     # per-package physics: with the heterogeneous rows resident in VMEM,
     # every pole/η/ΣG/poll constant becomes a [tp, blk] plane read; the
@@ -217,7 +222,7 @@ def _kernel(rho_ref, gamma_ref, buf0_ref, th0_ref, stats0_ref, freq0_ref,
             sel = (rows % tp == tiles).astype(jnp.float32)
             age = (rows // tp).astype(jnp.float32)
             ring = ring_scr[...]
-            mm = lambda m: jnp.dot(m, ring,
+            mm = lambda m: jnp.dot(m, ring, precision=hi,
                                    preferred_element_type=jnp.float32)
             return (mm(sel), mm(sel * (age - tm)),
                     mm(sel * (age >= w - q).astype(jnp.float32)))
@@ -323,14 +328,17 @@ def _kernel(rho_ref, gamma_ref, buf0_ref, th0_ref, stats0_ref, freq0_ref,
             # hysteresis in thr_scr), healthy lanes take the v24 law — the
             # plant steps ONCE at the per-lane blended frequency.  With
             # deg all-zero every `where` takes the v24 branch bitwise.
-            deg_b = deg > 0.5                                # [1, blk]
+            # Mosaic cannot select between boolean planes, nor broadcast a
+            # [1, blk] mask inside a select: the mode mask is broadcast to
+            # the tile plane once, and the latch is masked with `&`.
+            deg_b = jnp.broadcast_to(deg, (tp, deg.shape[-1])) > 0.5
             temp = plant(jnp.where(deg_b, f_prev, freq))
             step_g = step0_ref[0, 0].astype(jnp.int32) + step
             polled = (step_g % poll_l) == 0
             trig = (temp >= p.t_crit_c) & polled
             cool = (temp <= p.resume_below_c) & polled
             thr = thr_scr[...] > 0.5
-            thr_n = jnp.where(deg_b, (thr | trig) & ~cool, False)
+            thr_n = deg_b & (thr | trig) & ~cool
             freq = jnp.where(
                 deg_b,
                 jnp.where(thr_n, p.throttle_level,
@@ -343,7 +351,7 @@ def _kernel(rho_ref, gamma_ref, buf0_ref, th0_ref, stats0_ref, freq0_ref,
                 jnp.where(real, (temp > p.t_crit_c).astype(jnp.float32),
                           0.0),
                 axis=0, keepdims=True)
-            e_scr[...] = e_scr[...] + jnp.where(deg_b, fresh, crossed)
+            e_scr[...] = e_scr[...] + jnp.where(deg > 0.5, fresh, crossed)
             thr_scr[...] = thr_n.astype(jnp.float32)
             f_scr[...] = freq
             temp_ref[pl.ds(i, 1)] = temp[None]

@@ -10,13 +10,9 @@ import jax
 
 
 def make_mesh_compat(shape, axes):
-    """`jax.make_mesh` across jax versions: `axis_types`/`AxisType` only
-    exist in newer releases, and 0.4.x defaults to the same Auto axes."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return jax.make_mesh(shape, axes)
+    """`jax.make_mesh` with every axis `Auto` (GSPMD-propagated)."""
     return jax.make_mesh(shape, axes,
-                         axis_types=(axis_type.Auto,) * len(axes))
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -61,6 +61,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.compile_cache import use_compile_cache
 from repro.configs import get_arch, reduced
 from repro.configs.base import ShapeConfig
 from repro.core.density import rho_v24
@@ -408,6 +409,7 @@ def _chaos_soak(args):
 
 
 def main(argv=None):
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="gemma-2b")
     ap.add_argument("--reduced", action="store_true")

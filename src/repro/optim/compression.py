@@ -18,7 +18,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-# one cross-version checks-off shard_map wrapper for the whole repo
+# the repo's one checks-off shard_map wrapper
 from repro.distributed.sharding import fleet_shard_map as _shard_map
 
 

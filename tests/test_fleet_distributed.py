@@ -227,7 +227,7 @@ def test_distributed_sharded_fused_matches_vmap_oracle():
     single-host sharded_fused 90k gates already bound, not a distribution
     effect)."""
     res = _run_group("sharded_fused", 2, trace="uniform")
-    assert res["describe"] == "sharded_fused[4dev/2proc,blk=128]"
+    assert res["describe"] == "sharded_fused[4dev/2proc,blk=128,interpret]"
     _check_records(res["flushed"], _oracle(trace="uniform"))
 
 

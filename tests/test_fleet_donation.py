@@ -73,6 +73,48 @@ print("GUARD-OK flushes=%d" % stats.flushes)
 """
 
 
+_BANK_WORKER = r"""
+import numpy as np
+import jax
+import jax.numpy as jnp
+from repro.core import thermal
+from repro.core.scheduler import SchedulerConfig
+from repro.fleet import FleetEngine
+
+n, tiles = 4, 2
+trace = jnp.full((40, n, tiles), 1.5, jnp.float32)
+banks = thermal.pole_bank(jnp.linspace(0.3, 0.5, n * tiles).reshape(n, tiles),
+                          jnp.full((n, tiles), 80.0))
+surveys = []
+for mode in ("reactive_poll", "v24"):      # the Monte-Carlo harness's pair
+    eng = FleetEngine(SchedulerConfig(n_tiles=tiles, mode=mode,
+                                      heterogeneous=True, two_pole=False,
+                                      use_coupling=False),
+                      backend="broadcast", donate_state=True)
+    pkg = eng.sched.package_params(banks, batch_shape=(n,))
+    state = eng.init(n, pkg=pkg)
+    leaves = jax.tree_util.tree_leaves(state)
+    _, sv = eng.run_survey(state, trace)
+    surveys.append(np.asarray(sv.peak_t_c))
+    if not all(l.is_deleted() for l in leaves if isinstance(l, jax.Array)):
+        print("NODELETE")
+        raise SystemExit(0)
+    assert not banks.decay.is_deleted() and not pkg.decay.is_deleted()
+print("BANK-OK", np.isfinite(np.stack(surveys)).all())
+"""
+
+
+def test_donated_state_leaves_caller_pole_bank_alive():
+    """`init(pkg=...)` copies the caller's draws into the state, so a
+    donating survey deletes the state's buffers and never the caller's
+    pole bank, which the Monte-Carlo harness reuses for its second fleet."""
+    out = multihost.run_process_group(_BANK_WORKER, 1, local_devices=1,
+                                      timeout=300.0)[0]
+    if "NODELETE" in out:
+        pytest.skip("XLA declined state donation on this platform")
+    assert "BANK-OK True" in out, out
+
+
 def test_donated_buffers_deleted_and_guard_fires_across_flushes():
     out = multihost.run_process_group(_WORKER, 1, local_devices=1,
                                       timeout=300.0)[0]

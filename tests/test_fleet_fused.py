@@ -109,6 +109,46 @@ def test_fused_run_chunked_and_stream_continuity():
     _assert_states_equiv(s2, s1)
 
 
+def test_fused_merged_branch_mixes_lane_modes_in_one_block():
+    """The kernel's merged fallback/pinned branch with every lane kind in
+    ONE package block: pinned clean lanes bit-match a fused reactive_poll
+    fleet, healthy unpinned lanes bit-match a fused plain-v24 fleet, and
+    the stale-hint lanes (one pinned, one not) match the pure path with
+    events, staleness and degraded latches exact."""
+    n, t, tiles = 8, 96, 2
+    cfg = dict(n_tiles=tiles, mode="v24", filtration_window=16,
+               stale_limit_steps=4, recover_steps=8)
+    trace = np.array(_trace(t, n, tiles, seed=7))
+    trace[32:48, 2, :] = np.nan          # unpinned lane goes dark, recovers
+    trace[40:52, 5, 1] = np.inf          # pinned lane with a corrupt tile
+    pin = np.zeros(n, bool)
+    pin[[0, 3, 5]] = True
+    clean = [i for i in range(n) if i not in (2, 5)]
+
+    def run(backend, **kw):
+        e = FleetEngine(SchedulerConfig(**{**cfg, **kw}), backend=backend)
+        st = e.init(n)
+        if st.ctrl_mode is not None:
+            st = st._replace(ctrl_mode=jnp.asarray(pin))
+        st, temps, freqs = e.block_traces(st, jnp.asarray(trace))
+        return st, np.asarray(temps), np.asarray(freqs)
+
+    merged = dict(mixed_mode=True, degraded_fallback=True)
+    sf, tf, ff = run("fused", **merged)
+    sb, tb, fb = run("broadcast", **merged)
+    np.testing.assert_allclose(tf, tb, **TOL)
+    np.testing.assert_allclose(ff, fb, **TOL)
+    for f in ("events", "stale", "degraded"):
+        np.testing.assert_array_equal(np.asarray(getattr(sf, f)),
+                                      np.asarray(getattr(sb, f)), err_msg=f)
+    _, t_rp, f_rp = run("fused", mode="reactive_poll")
+    _, t_v, f_v = run("fused")
+    for lane in clean:
+        want_t, want_f = (t_rp, f_rp) if pin[lane] else (t_v, f_v)
+        assert np.array_equal(tf[:, lane], want_t[:, lane]), f"lane {lane}"
+        assert np.array_equal(ff[:, lane], want_f[:, lane]), f"lane {lane}"
+
+
 def test_fused_step_fallback_matches_broadcast():
     """Per-step `step()` on the fused backend is the pure-JAX fallback."""
     cfg = SchedulerConfig(n_tiles=4, mode="v24")

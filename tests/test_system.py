@@ -135,6 +135,27 @@ def test_serve_driver_smoke(capsys):
     assert all(1 <= a <= 2 for a in out["admitted"])
 
 
+def test_compile_cache_follows_env_else_fixed_repo_dir(monkeypatch,
+                                                       tmp_path):
+    from pathlib import Path
+
+    from repro.compile_cache import CACHE_DIR, use_compile_cache
+    repo = Path(__file__).resolve().parents[1]
+    prev = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        jax.config.update("jax_compilation_cache_dir", prev)
+        assert use_compile_cache() == str(tmp_path)
+        assert jax.config.jax_compilation_cache_dir == prev   # nothing set
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        assert use_compile_cache() == str(repo / ".jax_cache")
+        assert jax.config.jax_compilation_cache_dir == str(CACHE_DIR)
+        assert use_compile_cache() == str(CACHE_DIR)           # fixed path
+        assert ".jax_cache/" in (repo / ".gitignore").read_text().split()
+    finally:
+        jax.config.update("jax_compilation_cache_dir", prev)
+
+
 def test_train_driver_smoke(tmp_path):
     from repro.launch import train
     state = train.main(["--arch", "musicgen-large", "--reduced",
