@@ -46,6 +46,7 @@ import jax.numpy as jnp
 from repro.core import dvfs, thermal, workload
 from repro.core.fingerprint import FINGERPRINT, Fingerprint
 from repro.core.scheduler import SchedulerConfig
+from repro.tracing import span
 
 _TILE_PACK = 8      # f32 sublane width — trials packed per fleet package
 
@@ -59,22 +60,23 @@ class MCResult(NamedTuple):
     perf_v24: jnp.ndarray           # [N]
 
     def stats(self) -> dict:
-        b, v = self.peak_t_baseline, self.peak_t_v24
-        return {
-            "baseline_mean_c": float(b.mean()),
-            "baseline_std_c": float(b.std()),
-            "baseline_time_above_frac": float(self.time_above_baseline.mean()),
-            "v24_mean_c": float(v.mean()),
-            "v24_std_c": float(v.std()),
-            "v24_time_above_frac": float(self.time_above_v24.mean()),
-            "sigma_ratio": float(v.std() / b.std()),
-            "sigma_tighter_x": float(b.std() / v.std()),
-            "uplift_mean": float((self.perf_v24 / self.perf_baseline).mean() - 1),
-            "uplift_p5": float(jnp.percentile(
-                self.perf_v24 / self.perf_baseline - 1, 5)),
-            "uplift_p95": float(jnp.percentile(
-                self.perf_v24 / self.perf_baseline - 1, 95)),
-        }
+        with span("mc.stats"):
+            b, v = self.peak_t_baseline, self.peak_t_v24
+            up = self.perf_v24 / self.perf_baseline
+            return {
+                "baseline_mean_c": float(b.mean()),
+                "baseline_std_c": float(b.std()),
+                "baseline_time_above_frac": float(
+                    self.time_above_baseline.mean()),
+                "v24_mean_c": float(v.mean()),
+                "v24_std_c": float(v.std()),
+                "v24_time_above_frac": float(self.time_above_v24.mean()),
+                "sigma_ratio": float(v.std() / b.std()),
+                "sigma_tighter_x": float(b.std() / v.std()),
+                "uplift_mean": float(up.mean() - 1),
+                "uplift_p5": float(jnp.percentile(up - 1, 5)),
+                "uplift_p95": float(jnp.percentile(up - 1, 95)),
+            }
 
 
 def _ar1(z: jnp.ndarray, corr: float) -> jnp.ndarray:
@@ -232,30 +234,30 @@ def run(key=None, n_trials: int = 2_000, n_steps: int = 3_000,
     # at import and shared by every caller (the FleetEngine bug class)
     cfg = dvfs.DVFSConfig() if cfg is None else cfg
     key = jax.random.PRNGKey(2_000) if key is None else key
-    k_par, k_tr = jax.random.split(key)
-    rth, tau, util, poll = sample_params(k_par, n_trials, fp, corr=corr)
-    trial_keys = jax.random.split(k_tr, n_trials)
-
     lanes = _pack(n_trials)
     n_pkg = n_trials // lanes
-    traces = _trial_traces(trial_keys, util, n_steps, kind, fp)   # [N, T]
-    fleet_trace = traces.T.reshape(n_steps, n_pkg, lanes)
-
     lane_shape = (n_pkg, lanes)
-    banks = thermal.pole_bank(rth.reshape(lane_shape),
-                              tau.reshape(lane_shape), cfg.dt_ms)
+    with span("mc.sample"):
+        k_par, k_tr = jax.random.split(key)
+        rth, tau, util, poll = sample_params(k_par, n_trials, fp, corr=corr)
+        trial_keys = jax.random.split(k_tr, n_trials)
+        traces = _trial_traces(trial_keys, util, n_steps, kind, fp)  # [N, T]
+        fleet_trace = traces.T.reshape(n_steps, n_pkg, lanes)
+        banks = thermal.pole_bank(rth.reshape(lane_shape),
+                                  tau.reshape(lane_shape), cfg.dt_ms)
 
     def survey(mode: str):
-        eng = engine(n_trials, mode, backend=backend, devices=devices,
-                     cfg=cfg, fp=fp, filtration_impl=filtration_impl,
-                     plant=plant)
-        pkg = None
-        if plant == "pole":
-            pkg = eng.sched.package_params(
-                banks, poll_ticks=poll.reshape(lane_shape),
-                batch_shape=(n_pkg,))
-        # the oracle seeds each trial's ring with its opening density
-        state = eng.init(n_pkg, pkg=pkg, filtration_fill=fleet_trace[0])
+        with span("mc.setup"):
+            eng = engine(n_trials, mode, backend=backend, devices=devices,
+                         cfg=cfg, fp=fp, filtration_impl=filtration_impl,
+                         plant=plant)
+            pkg = None
+            if plant == "pole":
+                pkg = eng.sched.package_params(
+                    banks, poll_ticks=poll.reshape(lane_shape),
+                    batch_shape=(n_pkg,))
+            # the oracle seeds each trial's ring with its opening density
+            state = eng.init(n_pkg, pkg=pkg, filtration_fill=fleet_trace[0])
         _, sv = eng.run_survey(state, fleet_trace, burn_in=burn_in)
         return sv
 

@@ -85,6 +85,7 @@ from repro.core.fingerprint import FINGERPRINT, Fingerprint
 from repro.core.scheduler import (SchedulerConfig, SchedulerOutput,
                                   SchedulerState, ThermalScheduler)
 from repro.fleet.backends import FleetBackend, backend_class
+from repro.tracing import span
 
 
 class FleetTelemetry(NamedTuple):
@@ -313,11 +314,12 @@ class FleetEngine:
         """One jitted call: scan a [K, n, tiles] chunk and return the state
         plus the chunk's SINGLE reduced telemetry record (the streaming
         ingest loop's unit of work — one host sync per block)."""
-        self._guard_donated(state)
-        self._check_trace(rho_trace)
-        state, telem = self._run_block(state, rho_trace,
-                                       self._active(state, active))
-        self._debug_check_finite(state, telem)
+        with span("engine.run_block"):
+            self._guard_donated(state)
+            self._check_trace(rho_trace)
+            state, telem = self._run_block(state, rho_trace,
+                                           self._active(state, active))
+            self._debug_check_finite(state, telem)
         return state, telem
 
     def run_survey(self, state: SchedulerState, rho_trace, burn_in: int = 0,

@@ -43,6 +43,7 @@ import numpy as np
 
 from repro.core.scheduler import SchedulerState
 from repro.fleet.engine import FleetEngine
+from repro.tracing import span
 
 
 @dataclasses.dataclass
@@ -186,7 +187,8 @@ def stream(engine: FleetEngine, state: SchedulerState,
             if chunk is None:
                 exhausted = True
                 return
-            q.offer(engine.backend_impl.put_trace(chunk))
+            with span("ingest.put"):
+                q.offer(engine.backend_impl.put_trace(chunk))
             stats.chunks_ingested += 1
             stats.queue_peak = max(stats.queue_peak, len(q))
 

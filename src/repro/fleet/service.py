@@ -98,6 +98,7 @@ from repro.fleet.alerts import AlertEngine, tenant_window_stats
 from repro.fleet.engine import FleetEngine
 from repro.fleet.ingest import HintQueue, merge_sources
 from repro.fleet.registry import FleetRegistry, LaneProfile
+from repro.tracing import span
 
 __all__ = ["FleetService", "serve_http"]
 
@@ -494,7 +495,6 @@ class FleetService:
         the mask keeps them out of telemetry).  Returns the chunk plus the
         tenants fed this flush (recorded in the flush record)."""
         cap, tiles = self.registry.capacity, self.cfg.n_tiles
-        chunk = np.full((n_steps, cap, tiles), self.pad_rho, np.float32)
         fed: dict[str, np.ndarray] = {}
         for tenant, q in self._feeds.items():
             if len(q) and tenant in self.registry.tenants:
@@ -504,18 +504,20 @@ class FleetService:
         for tname, rho in fed.items():
             for pkg in tenants[tname].packages:
                 fed_lanes[self.registry.lane(pkg)] = iter([rho])
-        merged = (next(merge_sources(fed_lanes, cap, tiles,
-                                     pad_rho=self.pad_rho))
-                  if fed_lanes else None)
-        for pkg, lane in self.registry.packages.items():
-            if merged is not None and lane in fed_lanes:
-                chunk[:, lane, :] = merged[:, lane, :]
-                continue
-            key = jax.random.fold_in(
-                jax.random.PRNGKey(self._seed + self._pkg_key[pkg]),
-                self.flushes)
-            chunk[:, lane, :] = np.asarray(self._make_trace(
-                key, n_steps, self._kind_of[pkg], tiles))
+        with span("service.chunk"):
+            chunk = np.full((n_steps, cap, tiles), self.pad_rho, np.float32)
+            merged = (next(merge_sources(fed_lanes, cap, tiles,
+                                         pad_rho=self.pad_rho))
+                      if fed_lanes else None)
+            for pkg, lane in self.registry.packages.items():
+                if merged is not None and lane in fed_lanes:
+                    chunk[:, lane, :] = merged[:, lane, :]
+                    continue
+                key = jax.random.fold_in(
+                    jax.random.PRNGKey(self._seed + self._pkg_key[pkg]),
+                    self.flushes)
+                chunk[:, lane, :] = np.asarray(self._make_trace(
+                    key, n_steps, self._kind_of[pkg], tiles))
         return chunk, sorted(fed)
 
     def tick(self, chunk=None) -> dict | None:
@@ -549,31 +551,32 @@ class FleetService:
                 flush=self.flushes, step=step0, slot_names=names,
                 stats=stats_h._asdict(), alarms=alarms_h,
                 thresholds=self.registry.threshold_arrays())
-            # coerce numpy leaves to plain python here — TelemetryLog's
-            # _jsonable does not recurse into the nested dicts
-            tdict = {k: (int(v) if k in ("n_packages", "degraded_count")
-                         else float(v))
-                     for k, v in telem_h._asdict().items()}
-            sdict = stats_h._asdict()
-            record = {
-                "kind": "flush", "flush": self.flushes,
-                "capacity": cap,
-                "active": self.registry.active_mask().astype(int).tolist(),
-                "attached": [int(l) for l in self._attached_since_flush],
-                "surgery": list(self._surgery_since_flush),
-                "telemetry": tdict,
-                "tenants": {
-                    names[s]: {k: (int(v[s]) if k in ("n_lanes", "events",
-                                                      "degraded_lanes")
-                                   else float(v[s]))
-                               for k, v in sdict.items()}
-                    for s in range(self.registry.max_tenants)
-                    if names[s] is not None and sdict["n_lanes"][s] > 0},
-                "alerts": fired,
-                "ingest_fed": fed,
-                "rho": chunk.tolist(),
-            }
-            self.log.record(step0, **record)
+            with span("service.record"):
+                # coerce numpy leaves to plain python here — TelemetryLog's
+                # _jsonable does not recurse into the nested dicts
+                tdict = {k: (int(v) if k in ("n_packages", "degraded_count")
+                             else float(v))
+                         for k, v in telem_h._asdict().items()}
+                sdict = stats_h._asdict()
+                record = {
+                    "kind": "flush", "flush": self.flushes,
+                    "capacity": cap,
+                    "active": self.registry.active_mask().astype(int).tolist(),
+                    "attached": [int(l) for l in self._attached_since_flush],
+                    "surgery": list(self._surgery_since_flush),
+                    "telemetry": tdict,
+                    "tenants": {
+                        names[s]: {k: (int(v[s]) if k in ("n_lanes", "events",
+                                                          "degraded_lanes")
+                                       else float(v[s]))
+                                   for k, v in sdict.items()}
+                        for s in range(self.registry.max_tenants)
+                        if names[s] is not None and sdict["n_lanes"][s] > 0},
+                    "alerts": fired,
+                    "ingest_fed": fed,
+                    "rho": chunk.tolist(),
+                }
+                self.log.record(step0, **record)
             self._attached_since_flush = []
             self._surgery_since_flush = []
             self.flushes += 1
@@ -887,13 +890,18 @@ class FleetService:
 
     # ----------------------------------------------------------------- intro
     def snapshot(self, last: int = 1) -> dict:
-        with self.lock:
+        # an operator read's wait for the lock, behind GET /telemetry
+        with span("service.lock_wait.snapshot"):
+            self.lock.acquire()
+        try:
             recs = self.log.rows()[-last:]
             return {"flushes": self.flushes,
                     "capacity": self.registry.capacity,
                     "n_active": self.registry.n_active,
                     "records": [{k: v for k, v in r.items() if k != "rho"}
                                 for r in recs]}
+        finally:
+            self.lock.release()
 
     def shutdown(self) -> None:
         self._shutdown.set()

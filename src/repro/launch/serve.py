@@ -90,7 +90,6 @@ def _montecarlo(args):
     margins derived from the measured σ ratio.
     """
     from repro.core import guardband, montecarlo
-    t0 = time.time()
     r = montecarlo.run(n_trials=args.montecarlo, n_steps=args.mc_steps,
                        key=jax.random.PRNGKey(args.seed),
                        backend=args.fleet_backend,
@@ -98,11 +97,9 @@ def _montecarlo(args):
                        filtration_impl=args.filtration,
                        plant=args.plant)
     s = r.stats()
-    dt = time.time() - t0
     print(f"[mc] {args.montecarlo} trials x {args.mc_steps} steps "
           f"(paired baseline+v24) on '{args.fleet_backend}' "
-          f"plant '{args.plant}' in {dt:.1f} s "
-          f"({args.montecarlo / dt:.0f} trials/s)")
+          f"plant '{args.plant}'")
     print(f"[mc] baseline peak-T {s['baseline_mean_c']:.1f}C "
           f"sigma {s['baseline_std_c']:.2f}C, exceedance "
           f"{s['baseline_time_above_frac'] * 100:.1f}%")
@@ -115,7 +112,7 @@ def _montecarlo(args):
     for g in guardband.from_montecarlo(s):
         print(f"[mc] guard-band {g.category}: {g.margin_before * 100:.0f}% "
               f"-> {g.margin_after * 100:.1f}% (-{g.reduction_pct:.1f}%)")
-    return {"montecarlo": s, "trials_per_s": args.montecarlo / dt}
+    return {"montecarlo": s}
 
 
 def _stream_soak(args, sched_cfg: SchedulerConfig, rho: float, key):
@@ -156,7 +153,6 @@ def _stream_soak(args, sched_cfg: SchedulerConfig, rho: float, key):
            if args.distributed else "[stream]")
     print(f"{tag} backend {eng.backend_impl.describe()} "
           f"({eng.backend_impl.n_devices()} device(s)), fleet {n}")
-    t0 = time.time()
     if args.distributed:
         from repro.fleet import distributed_stream
         state, flushed, stats = distributed_stream(
@@ -166,14 +162,11 @@ def _stream_soak(args, sched_cfg: SchedulerConfig, rho: float, key):
         state, flushed, stats = stream(eng, state,
                                        chunk_source(trace, args.gen),
                                        on_flush=on_flush)
-    dt = time.time() - t0
-    rate = stats.steps * n / max(dt, 1e-9)
     print(f"{tag} done: {stats.steps} steps x {n} pkgs "
-          f"({eng.backend_impl.describe()}) in {dt*1e3:.0f} ms "
-          f"({rate:.0f} pkg-steps/s), {stats.host_syncs} host syncs / "
+          f"({eng.backend_impl.describe()}), {stats.host_syncs} host syncs / "
           f"{stats.flushes} flushes (contract: 1/flush)")
     return {"stream": flushed, "host_syncs": stats.host_syncs,
-            "flushes": stats.flushes, "pkg_steps_per_s": rate}
+            "flushes": stats.flushes}
 
 
 def _serve_resident(args, sched_cfg: SchedulerConfig):
