@@ -85,6 +85,7 @@ from repro.core.fingerprint import FINGERPRINT, Fingerprint
 from repro.core.scheduler import (SchedulerConfig, SchedulerOutput,
                                   SchedulerState, ThermalScheduler)
 from repro.fleet.backends import FleetBackend, backend_class
+from repro.fleet.quantiles import fleet_percentiles
 from repro.tracing import span
 
 
@@ -462,21 +463,6 @@ class FleetEngine:
             rho = rho[:, None]
         return jnp.broadcast_to(rho, (n, self.cfg.n_tiles))
 
-    @staticmethod
-    def _masked_quantile(sorted_v: jnp.ndarray, cnt, q: float) -> jnp.ndarray:
-        """Linear-interpolated percentile over the first ``cnt`` entries of an
-        ascending-sorted last axis (inactive lanes sort to +inf past them) —
-        numpy's default interpolation, computed with a traced count so mask
-        flips never re-specialise the program."""
-        pos = q / 100.0 * (cnt - 1).astype(sorted_v.dtype)
-        lo = jnp.floor(pos).astype(jnp.int32)
-        hi = jnp.ceil(pos).astype(jnp.int32)
-        frac = pos - lo
-        take = lambda i: jnp.take_along_axis(
-            sorted_v, jnp.broadcast_to(i, sorted_v.shape[:-1])[..., None],
-            axis=-1)[..., 0]
-        return take(lo) * (1.0 - frac) + take(hi) * frac
-
     def _degraded_count(self, state: SchedulerState, active=None):
         """Active lanes currently on the reactive fallback (int32 scalar;
         0 whenever degraded_fallback is off)."""
@@ -496,7 +482,7 @@ class FleetEngine:
         fcnt = cnt.astype(out.temp_c.dtype)
         temp = out.temp_c.reshape(-1)
         freq = out.freq.reshape(-1)
-        sorted_t = jnp.sort(jnp.where(mf, temp, jnp.inf))
+        p50, p99 = fleet_percentiles(out.temp_c[None], m[None], cnt)
         mu = jnp.where(mf, temp, 0.0).sum() / fcnt
         rtok = jnp.broadcast_to(rtok_from_rho(rho),
                                 out.temp_c.shape).reshape(-1)
@@ -506,8 +492,8 @@ class FleetEngine:
             n_packages=active.sum().astype(jnp.int32),
             events_total=ev_total,
             events_step=ev_total - prev_events,
-            temp_p50_c=self._masked_quantile(sorted_t, cnt, 50.0),
-            temp_p99_c=self._masked_quantile(sorted_t, cnt, 99.0),
+            temp_p50_c=p50[0],
+            temp_p99_c=p99[0],
             temp_max_c=jnp.where(mf, temp, -jnp.inf).max(),
             temp_var_c2=(jnp.where(mf, (temp - mu) ** 2, 0.0).sum() / fcnt),
             freq_mean=jnp.where(mf, freq, 0.0).sum() / fcnt,
@@ -533,13 +519,14 @@ class FleetEngine:
                 rho, out, prev_events, state.events, active,
                 self._degraded_count(state, active))
         rtok = rtok_from_rho(rho)                    # [n_packages, n_tiles]
+        p50, p99 = fleet_percentiles(out.temp_c[None])
         telem = FleetTelemetry(
             degraded_count=self._degraded_count(state),
             n_packages=jnp.asarray(state.freq.shape[0], jnp.int32),
             events_total=state.events.sum(),
             events_step=state.events.sum() - prev_events,
-            temp_p50_c=jnp.percentile(out.temp_c, 50.0),
-            temp_p99_c=jnp.percentile(out.temp_c, 99.0),
+            temp_p50_c=p50[0],
+            temp_p99_c=p99[0],
             temp_max_c=out.temp_c.max(),
             temp_var_c2=out.temp_c.var(),
             freq_mean=out.freq.mean(),
@@ -779,13 +766,14 @@ class FleetEngine:
         flat = lambda x: x.reshape(t, -1)
         rtok = rtok_from_rho(rho_trace)
         if active is None:
+            p50, p99 = fleet_percentiles(temps)
             return FleetTelemetry(
                 degraded_count=deg_count,
                 n_packages=jnp.full((t,), n, jnp.int32),
                 events_total=prev_events + jnp.cumsum(ev_step),
                 events_step=ev_step,
-                temp_p50_c=jnp.percentile(flat(temps), 50.0, axis=1),
-                temp_p99_c=jnp.percentile(flat(temps), 99.0, axis=1),
+                temp_p50_c=p50,
+                temp_p99_c=p99,
                 temp_max_c=flat(temps).max(axis=1),
                 temp_var_c2=flat(temps).var(axis=1),
                 freq_mean=flat(freqs).mean(axis=1),
@@ -799,7 +787,7 @@ class FleetEngine:
         cnt = jnp.maximum(mf.sum(), 1)
         fcnt = cnt.astype(temps.dtype)
         tf, ff = flat(temps), flat(freqs)
-        sorted_t = jnp.sort(jnp.where(mf[None, :], tf, jnp.inf), axis=1)
+        p50, p99 = fleet_percentiles(temps, active[None, :, None], cnt)
         mu = jnp.where(mf, tf, 0.0).sum(axis=1) / fcnt
         msum = lambda x: jnp.where(mf, x, 0.0).sum(axis=1)
         return FleetTelemetry(
@@ -808,8 +796,8 @@ class FleetEngine:
             * active.sum().astype(jnp.int32),
             events_total=prev_events + jnp.cumsum(ev_step),
             events_step=ev_step,
-            temp_p50_c=self._masked_quantile(sorted_t, cnt, 50.0),
-            temp_p99_c=self._masked_quantile(sorted_t, cnt, 99.0),
+            temp_p50_c=p50,
+            temp_p99_c=p99,
             temp_max_c=jnp.where(mf, tf, -jnp.inf).max(axis=1),
             temp_var_c2=msum((tf - mu[:, None]) ** 2) / fcnt,
             freq_mean=msum(ff) / fcnt,
