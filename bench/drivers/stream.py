@@ -3,7 +3,11 @@
 Set-up builds a `FleetEngine` from the configuration, makes a pool of
 distinct density chunks on the device from the seed (package i runs the
 workload kind ``KINDS[i % 4]``), fetches them to the host once, and warms
-the flush program on a throw-away fleet state.
+the flush program on a throw-away fleet state.  Each chunk stays in the
+host buffer that the runtime's device-to-host copy filled: on a TPU v5e a
+buffer the CPU wrote instead (a page-aligned mapping of its own) uploaded
+at 8.4-9.3 GB/s and spread more, against 10 GB/s (PERF.md).  Last,
+each chunk is uploaded once alone, blocking, for the notes.
 
 The window is one `stream()` call over the pool, cycled, from a fresh
 fleet state: every flush uploads a host chunk through the program's
@@ -12,6 +16,13 @@ telemetry in one host sync.  A flush's latency runs from the engine taking
 its chunk (the `run_block` call) to its telemetry record on the host.  The
 source stops handing out chunks once the window's seconds are over; every
 flush handed out counts.
+
+The notes: ``[flush]``, the flushes' latency p50, p95 and max, and p50/p95
+by pool chunk (flush index mod ``pool_chunks``); ``[ingest]``, the time of
+each chunk's lone upload in set-up and its GB/s; ``[pool]``, each chunk's
+buffer: its offset in its page and its huge pages against its resident
+size.  ``bench/run.py`` prints the ``[host]`` line:
+the CPUs the process may run on, their nodes and the chip's.
 
 The check replays the window from the same fresh state through the plain
 reference and compares the fleet telemetry of sampled flushes (the first,
@@ -29,6 +40,14 @@ from bench.harness import (SEED_SPAN, TELEMETRY_FIELDS, Phases, quantile,
                            rel_err, sampled)
 
 EVENT_COUNTS = ("events_total", "events_step")
+
+
+def upload_ms(chunk) -> float:
+    """Host-clock time of one blocking upload of a host chunk, in ms."""
+    import jax
+    t = time.perf_counter()
+    jax.device_put(chunk).block_until_ready()
+    return (time.perf_counter() - t) * 1e3
 
 
 class _TimedEngine:
@@ -66,7 +85,8 @@ class Driver:
 
     def make_pool(self) -> None:
         """The traffic's distinct [steps, n, tiles] chunks, made on the
-        device from the seed and fetched to the host once."""
+        device from the seed and fetched to the host once, each into the
+        host buffer the runtime's device-to-host copy fills."""
         import jax
 
         from bench.reference.workload import fleet_chunk
@@ -93,6 +113,8 @@ class Driver:
         self.state = self.engine.init(self.n)
         self.state.freq.block_until_ready()
         ph.mark("init")
+        self.upload_ms = [upload_ms(c) for c in self.pool]
+        ph.mark("uploads")
 
     def run(self, seconds: float) -> None:
         from repro.fleet import stream
@@ -125,20 +147,37 @@ class Driver:
                 "flush_p95_ms": quantile(self.lat_ms, 95)}
 
     def notes(self) -> list[str]:
+        from bench.placement import buffer_pages
         lat = self.lat_ms
+        k = len(self.pool)
+        per = "; ".join(
+            f"{j}: {quantile(lat[j::k], 50):.2f}/{quantile(lat[j::k], 95):.2f}"
+            for j in range(k))
+        mb = self.pool[0].nbytes / 1e6
+        ups = ", ".join(f"{t:.2f}" for t in self.upload_ms)
+        pages = "; ".join(
+            f"{j}: offset {p['offset']} huge {p['huge_mb']:.0f}/"
+            f"{p['rss_mb']:.0f} MB"
+            for j, p in enumerate(map(buffer_pages, self.pool)))
         return [
             self.phases.line(),
             f"[flush] {len(lat)} flushes of {self.n} packages x "
             f"{self.steps} steps x {self.cfg['scheduler']['n_tiles']} tiles; "
             f"latency p50 {quantile(lat, 50):.2f} ms p95 "
             f"{quantile(lat, 95):.2f} ms max {max(lat):.2f} ms; "
+            f"by pool chunk p50/p95 ms {per}; "
             f"{self.stats.host_syncs} host syncs",
+            f"[ingest] one {mb:.1f} MB chunk uploaded alone in set-up: "
+            f"{ups} ms ({mb / quantile(self.upload_ms, 50):.3f} GB/s median)",
+            f"[pool] {pages}",
         ]
 
     def trace_context(self) -> dict:
         from bench.kernel_bytes import flush_bytes
         return {"unit_span": self.unit_span, "unit_bytes": flush_bytes(
-            self.cfg["scheduler"], self.steps, self.n)}
+            self.cfg["scheduler"], self.steps, self.n),
+            "chunk_bytes": 4 * self.steps * self.n
+            * self.cfg["scheduler"]["n_tiles"]}
 
     def release(self) -> None:
         del self.state, self.engine

@@ -10,6 +10,11 @@ Run from the root of a checkout.  Cells, metrics and bounds are in
 ``breakdown``, and last ``checks``: each compared number with its limit).
 The compared numbers are also the last lines on stderr.  With no TPU, or
 fewer chips than the cell asks for, it exits nonzero and prints no result.
+
+Before JAX starts, the process binds itself to the CPUs of the chip's NUMA
+node where the host has several (`bench/placement.py`), so that every
+thread JAX starts, and every host buffer they first touch, sits on the
+chip's node whichever socket the OS started the process on.
 """
 import time
 
@@ -34,12 +39,19 @@ def main(argv=None) -> int:
               "repository", file=sys.stderr)
         return 2
     sys.path[:0] = [str(ROOT), str(ROOT / "src")]
+    from bench import harness, placement
+    try:
+        bm = harness.load_benchmark()
+        cell, entry = harness.find_cell(bm, args.workload)
+    except harness.BenchError as e:
+        print(f"bench: {e}", file=sys.stderr)
+        return 1
+    bound = placement.bind_to_chip_node(cell["chips"])
+
     from repro.compile_cache import use_compile_cache
     cache = use_compile_cache()
 
     import jax
-
-    from bench import harness
     try:
         devices = jax.devices()
     except RuntimeError as e:
@@ -50,12 +62,11 @@ def main(argv=None) -> int:
               file=sys.stderr)
         return 1
     try:
-        bm = harness.load_benchmark()
-        cell, entry = harness.find_cell(bm, args.workload)
         config = harness.load_config(entry)
         harness.say(f"[setup] {len(devices)} x {devices[0].device_kind}; "
                     f"cell {cell['name']} ({cell['chips']} chip(s)); "
                     f"compile cache {cache}")
+        harness.say(placement.placement_line(cell["chips"], bound))
         harness.run_cell(bm, cell, config, args.seed, args.seconds,
                          bool(args.trace), T_PROCESS, devices)
     except harness.BenchError as e:

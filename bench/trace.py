@@ -4,7 +4,9 @@ profiler's one clock.
 
 `Trace.from_xplane` reads the file with `jax.profiler.ProfileData`; `Trace`
 round-trips through a small JSON form (`to_json` / `from_json`), which is
-how the recorded test trace is kept.
+how the recorded test trace is kept.  Host-to-device copies are kept apart
+from the operations (`Trace.transfers`), so that they count in no op's
+busy time.
 """
 from __future__ import annotations
 
@@ -19,6 +21,12 @@ from dataclasses import dataclass, field
 SPAN_PREFIX = "bench."
 # lines of a TPU plane that hold one event per executed HLO operation
 OP_LINES = ("XLA Ops",)
+# A host-to-device copy shows on the host plane as two runtime events: its
+# issue on a ``pjrt-tpu-tasks`` thread and its completion, seen by the
+# runtime's event thread (bench/metrics/h2d_ms.py).  The TPU plane holds
+# no copy events.
+TRANSFER_ISSUE = "tpu::System::TransferToDevice"
+TRANSFER_DONE = "tpu::System::TransferToDevice=>IssueEvent=>Done"
 
 
 def parse_hlo(text: str) -> tuple[str, str]:
@@ -50,6 +58,34 @@ def _span(a: float, b: float) -> tuple[float, float]:
     return a, b - a
 
 
+def pair_transfers(marks) -> list:
+    """Copies from their runtime events: ``marks`` [(start, dur, is_done)];
+    each completion closes the earliest open issue that began before it (a
+    chunk's copies complete in the order they were issued).  Each copy is an
+    Op from its issue's start to its completion's end."""
+    out, open_ = [], []
+    for start, dur, done in sorted(marks):
+        if not done:
+            open_.append(start)
+        elif open_:
+            first = open_.pop(0)
+            out.append(Op("h2d", "transfer", first, start + dur - first, 0))
+    return out
+
+
+def union_ns(intervals) -> float:
+    """Length of the union of [start, end) intervals."""
+    total, cur_s, cur_e = 0.0, None, None
+    for s, e in sorted(intervals):
+        if cur_e is None or s > cur_e:
+            if cur_e is not None:
+                total += cur_e - cur_s
+            cur_s, cur_e = s, e
+        else:
+            cur_e = max(cur_e, e)
+    return total if cur_e is None else total + cur_e - cur_s
+
+
 @dataclass
 class Op:
     name: str           # HLO instruction name, e.g. "fusion.12", "sort.3"
@@ -76,6 +112,7 @@ class Trace:
     ops: list = field(default_factory=list)
     spans: list = field(default_factory=list)
     n_devices: int = 0
+    transfers: list = field(default_factory=list)   # Op: issue to done
 
     # ---------------------------------------------------------- reading
     @classmethod
@@ -83,7 +120,7 @@ class Trace:
         from jax.profiler import ProfileData
         pd = ProfileData.from_file(path)
         tr = cls()
-        dev_ids = {}
+        dev_ids, marks = {}, []
         for plane in pd.planes:
             if plane.name.startswith("/device:TPU:"):
                 dev = dev_ids.setdefault(plane.name, len(dev_ids))
@@ -100,8 +137,13 @@ class Trace:
                         if ev.name.startswith(SPAN_PREFIX):
                             tr.spans.append(Span(ev.name, float(ev.start_ns),
                                                  float(ev.duration_ns)))
+                        elif ev.name in (TRANSFER_ISSUE, TRANSFER_DONE):
+                            marks.append((float(ev.start_ns),
+                                          float(ev.duration_ns),
+                                          ev.name == TRANSFER_DONE))
         tr.n_devices = len(dev_ids)
         tr.ops.sort(key=lambda o: o.start)
+        tr.transfers = pair_transfers(marks)
         tr.spans.sort(key=lambda s: s.start)
         return tr
 
@@ -117,7 +159,9 @@ class Trace:
         data = {"n_devices": self.n_devices,
                 "ops": [[o.name, o.category, o.start, o.dur, o.device]
                         for o in self.ops],
-                "spans": [[s.name, s.start, s.dur] for s in self.spans]}
+                "spans": [[s.name, s.start, s.dur] for s in self.spans],
+                "transfers": [[o.name, o.category, o.start, o.dur, o.device]
+                              for o in self.transfers]}
         with gzip.open(path, "wt") as f:
             json.dump(data, f)
 
@@ -127,7 +171,8 @@ class Trace:
             data = json.load(f)
         return cls(ops=[Op(*o) for o in data["ops"]],
                    spans=[Span(*s) for s in data["spans"]],
-                   n_devices=data["n_devices"])
+                   n_devices=data["n_devices"],
+                   transfers=[Op(*o) for o in data.get("transfers", [])])
 
     def crop(self, start: float, end: float) -> "Trace":
         """The part of the trace in [start, end), spans clipped to it, with
@@ -141,7 +186,12 @@ class Trace:
                  and s.name != "bench.window"]
         spans.append(Span("bench.window", start, end - start))
         spans.sort(key=lambda s: s.start)
-        return Trace(ops=ops, spans=spans, n_devices=self.n_devices)
+        transfers = [Op(o.name, o.category,
+                        *_span(*clip(o.start, o.start + o.dur)), o.device)
+                     for o in self.transfers
+                     if o.start < end and o.start + o.dur > start]
+        return Trace(ops=ops, spans=spans, n_devices=self.n_devices,
+                     transfers=transfers)
 
     # ------------------------------------------------------- reductions
     def window(self, name: str = "bench.window") -> Span | None:
@@ -168,18 +218,8 @@ class Trace:
     def busy_ns(self, start: float, end: float, device: int) -> float:
         """Length of the union of ``device``'s op intervals, clipped to
         [start, end]."""
-        busy, cur_s, cur_e = 0.0, None, None
-        for o in self.ops_in(start, end, device):
-            s, e = max(o.start, start), min(o.start + o.dur, end)
-            if cur_e is None or s > cur_e:
-                if cur_e is not None:
-                    busy += cur_e - cur_s
-                cur_s, cur_e = s, e
-            else:
-                cur_e = max(cur_e, e)
-        if cur_e is not None:
-            busy += cur_e - cur_s
-        return busy
+        return union_ns((max(o.start, start), min(o.start + o.dur, end))
+                        for o in self.ops_in(start, end, device))
 
     def mean_busy_ns(self, start: float, end: float) -> float:
         """Busy time averaged over the devices that ran any operation."""

@@ -307,8 +307,7 @@ for _cell, _span in (("stream_pvc47_aurora", "bench.flush"),
 
 @pytest.mark.parametrize("cell,span", RECORDED)
 def test_reducers_on_a_recorded_trace(cell, span):
-    from bench.metrics import (device_idle_pct, fleet_step_roofline,
-                               telemetry_sort_ms)
+    from bench.metrics import device_idle_pct, fleet_step_roofline, h2d_ms
     tr = Trace.from_json(str(DATA / f"{cell}.trace.json.gz"))
     win = tr.window()
     busy = _busy(tr, win.start, win.end)
@@ -318,10 +317,12 @@ def test_reducers_on_a_recorded_trace(cell, span):
     units = [s for s in tr.spans if s.name == span]
     host = sum(s.dur - _busy(tr, s.start, s.end) for s in units) / len(units)
     assert tr.host_self_ms(span) == pytest.approx(host * 1e-6, rel=1e-9)
-    sorts = [o.dur for o in tr.ops if o.category == "sort"]
-    got = telemetry_sort_ms.read(tr, ctx)
-    if sorts:
-        assert got == pytest.approx(sum(sorts) / len(units) * 1e-6)
+    copies = _union([(max(o.start, win.start), min(o.start + o.dur, win.end))
+                     for o in tr.transfers
+                     if o.start < win.end and o.start + o.dur > win.start])
+    got = h2d_ms.read(tr, ctx)
+    if copies:
+        assert got == pytest.approx(copies / len(units) * 1e-6)
     else:
         assert got is None
     kern = [o.dur for o in tr.ops
@@ -373,3 +374,58 @@ def test_a_nan_never_passes_a_check():
     nan = {"telemetry": dict(rec["telemetry"], temp_p99_c=float("nan")),
            "tenants": {}}
     assert serve.compare([nan], [rec])["telemetry_err"] == float("inf")
+
+
+def _upload_trace(copies, flushes=3):
+    """A window of 1 s with ``flushes`` flushes, each of which uploads one
+    chunk, and host-to-device copies at ``copies`` [(start, dur)] ms."""
+    from bench.trace import Op, Span
+    ms = 1e6
+    spans = [Span("bench.window", 0.0, 1000 * ms)]
+    spans += [Span("bench.flush", (100 * i + 50) * ms, 80 * ms)
+              for i in range(flushes)]
+    transfers = [Op("copy", "h2d", a * ms, d * ms, 0) for a, d in copies]
+    return Trace(spans=sorted(spans, key=lambda s: s.start), n_devices=1,
+                 transfers=transfers)
+
+
+def test_h2d_ms_reads_the_mean_copy_per_upload(tmp_path, capsys):
+    from bench.metrics import h2d_ms
+    ctx = {"unit_span": "bench.flush", "chunk_bytes": 600e6}
+    # three uploads of 60, 60 and 50 ms; the second copy in two pieces that
+    # overlap by 10 ms; one copy outside the window counts nothing
+    tr = _upload_trace([(10, 60), (110, 35), (135, 35), (210, 50),
+                        (1200, 60)])
+    assert h2d_ms.read(tr, ctx) == pytest.approx((60 + 60 + 50) / 3)
+    assert "GB/s" in capsys.readouterr().out
+    # a copy that runs past the window's end counts up to it
+    assert h2d_ms.read(_upload_trace([(980, 60)]), ctx) == pytest.approx(
+        20 / 3)
+    # the copies survive the recorded form and a crop
+    tr.to_json(str(tmp_path / "t.json.gz"))
+    back = Trace.from_json(str(tmp_path / "t.json.gz"))
+    assert h2d_ms.read(back, ctx) == pytest.approx((60 + 60 + 50) / 3)
+    crop = tr.crop(0.0, 150e6)
+    assert [(o.start, o.dur) for o in crop.transfers] == [
+        (10e6, 60e6), (110e6, 35e6), (135e6, 15e6)]
+
+
+def test_h2d_ms_reads_nothing_without_copies_or_uploads():
+    from bench.metrics import h2d_ms
+    ctx = {"unit_span": "bench.flush", "chunk_bytes": 600e6}
+    assert h2d_ms.read(_upload_trace([]), ctx) is None
+    assert h2d_ms.read(_upload_trace([(10, 60)], flushes=0), ctx) is None
+    assert h2d_ms.read(_upload_trace([(1200, 60)]), ctx) is None
+    for cell in ("serve_synth_4k", "mc_sec10_2k"):
+        tr = Trace.from_json(str(DATA / f"{cell}.trace.json.gz"))
+        assert h2d_ms.read(tr, ctx) is None
+
+
+def test_copies_pair_each_completion_with_the_earliest_open_issue():
+    from bench.trace import pair_transfers
+    # two copies in flight at once, a completion with no issue before it
+    # (its issue left the trace) closes nothing
+    marks = [(5.0, 0.1, True), (10.0, 0.2, False), (40.0, 0.1, False),
+             (70.0, 0.5, True), (130.0, 0.5, True), (150.0, 0.1, False)]
+    got = [(o.start, o.dur) for o in pair_transfers(marks)]
+    assert got == [(10.0, 60.5), (40.0, 90.5)]

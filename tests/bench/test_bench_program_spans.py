@@ -20,7 +20,7 @@ NEW = {"chunk_synth_ms": "serve_synth_4k", "log_record_ms": "serve_synth_4k",
        "lock_wait_ms": "serve_synth_4k", "put_enqueue_ms": "stream_pvc47_aurora",
        "block_dispatch_ms": "stream_pvc47_aurora",
        "mc_setup_ms": "mc_sec10_2k", "mc_stats_ms": "mc_sec10_2k"}
-OLD = ("device_idle_pct", "tick_host_ms", "telemetry_sort_ms",
+OLD = ("device_idle_pct", "tick_host_ms", "h2d_ms",
        "fleet_step_roofline", "mc_host_ms")
 UNIT = {"serve_synth_4k": "bench.tick", "stream_pvc47_aurora": "bench.flush",
         "mc_sec10_2k": "bench.mc_run"}

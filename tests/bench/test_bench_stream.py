@@ -88,3 +88,27 @@ def test_stream_fault_is_not_correct(fault, monkeypatch):
     if number:
         c = r["checks"][number]
         assert c["value"] > c["limit"], r["checks"]
+
+
+def test_pool_holds_the_seeds_chunks():
+    """The pool holds the seed's distinct chunks as the jitted
+    `fleet_chunk` makes them, on the host."""
+    import jax
+    import numpy as np
+
+    from bench import harness
+    from bench.reference.workload import fleet_chunk
+    from bench_cells import tiny
+    _, _, cfg, traffic = tiny(CELL)
+    seed = 2 ** 31 + 11
+    drv = harness.driver_class(traffic)(cfg, traffic, seed, jax.devices())
+    drv.make_pool()
+    key = jax.random.PRNGKey(seed % harness.SEED_SPAN)
+    make = jax.jit(fleet_chunk, static_argnums=(1, 2, 3))
+    assert len(drv.pool) == traffic["pool_chunks"]
+    for j, got in enumerate(drv.pool):
+        want = np.asarray(make(jax.random.fold_in(key, j), cfg["flush_every"],
+                               16, cfg["scheduler"]["n_tiles"]))
+        assert isinstance(got, np.ndarray) and got.dtype == want.dtype
+        assert np.array_equal(got, want)
+    assert not np.array_equal(drv.pool[0], drv.pool[1])
